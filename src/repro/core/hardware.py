@@ -36,7 +36,8 @@ from repro.core.topology import (
 __all__ = [
     "DTYPE_BYTES", "HardwareSpec", "MemoryLevel", "Topology",
     "TPU_V5E", "TPU_V5P", "TPU_V4", "GPU_MI300X_LIKE", "GPU_H100_LIKE",
-    "PRESETS", "get_hardware", "calibrate", "validate_measured",
+    "PRESETS", "DEVICE_KIND_PRESETS", "get_hardware",
+    "preset_for_device_kind", "calibrate", "validate_measured",
 ]
 
 # ---------------------------------------------------------------------------
@@ -208,6 +209,24 @@ def get_hardware(name: str) -> Topology:
         return PRESETS[name]
     except KeyError:
         raise KeyError(f"unknown hardware {name!r}; presets: {sorted(PRESETS)}")
+
+
+# ``device_kind`` as JAX reports it -> preset name.  Only kinds whose
+# preset matches the silicon belong here: a kind that is missing has no
+# preset, and pricing it with another chip's peaks would be a silent lie.
+DEVICE_KIND_PRESETS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
+
+
+def preset_for_device_kind(kind: str) -> Topology:
+    """The preset for an attached device; an unknown kind is an error."""
+    try:
+        return PRESETS[DEVICE_KIND_PRESETS[kind]]
+    except KeyError:
+        raise KeyError(f"no hardware preset for device_kind {kind!r}; "
+                       f"known kinds: {sorted(DEVICE_KIND_PRESETS)}")
 
 
 # Numeric calibration fields that must be strictly positive — a measured

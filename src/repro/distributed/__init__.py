@@ -13,10 +13,9 @@ from repro.distributed.collectives import (
     choose_gemm_layout,
     ring_all_gather_s,
     ring_all_reduce_s,
-    tp_matmul,
 )
 
 __all__ = ["constrain", "get_mesh", "set_mesh", "batch_shardings", "cache_shardings", "opt_shardings",
            "param_shardings", "replicated", "rules_for", "spec_for",
            "LayoutChoice", "choose_gemm_layout", "ring_all_gather_s",
-           "ring_all_reduce_s", "tp_matmul"]
+           "ring_all_reduce_s"]

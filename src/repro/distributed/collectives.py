@@ -7,31 +7,19 @@ decision procedure, one level up the hierarchy:
 
     per-chip GEMM latency (paper model)  vs  collective latency (ring model)
 
-``tp_matmul`` is the deployment shape for the Pallas kernel under TP: a
-shard_map whose *local* shapes feed the selector (per-chip-optimal tiles)
-followed by the psum the layout chooser priced.
+The deployment shape these layouts price — the Pallas kernel under
+shard_map on *local* shapes, followed by the psum of a K-split — is the
+boundary ``nn.layers.dense`` puts around every layer GEMM on a mesh.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple
-
-import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                   # jax >= 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from typing import Tuple
 
 from repro.core.dtypes import DTYPE_BYTES
 from repro.core.hardware import TPU_V5E
 from repro.core.topology import HardwareSpec
-from repro.core.latency import GemmProblem
 from repro.core.selector import select_gemm_config
-from repro.kernels import ops as kops
 
 
 def ring_all_reduce_s(nbytes: float, n: int, hw: HardwareSpec) -> float:
@@ -80,28 +68,3 @@ def choose_gemm_layout(M: int, N: int, K: int, n_chips: int,
         cands.append(LayoutChoice("replicated", sel.predicted.total,
                                   (M, N, K), 0.0))
     return min(cands, key=lambda c: c.predicted_s)
-
-
-def tp_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, axis: str = "model",
-              *, reduce_k: bool = False, backend: Optional[str] = None
-              ) -> jax.Array:
-    """Tensor-parallel GEMM via shard_map: the selector sees LOCAL shapes.
-
-    reduce_k=False: w column-sharded (D, F/axis) -> output sharded on F.
-    reduce_k=True : w row-sharded (D/axis, F), x sharded on D -> psum."""
-    if reduce_k:
-        in_specs = (P(None, axis), P(axis, None))
-        out_spec = P(None, None)
-
-        def f(xl, wl):
-            y = kops.matmul(xl, wl, backend=backend, out_dtype=jnp.float32)
-            return jax.lax.psum(y, axis)
-    else:
-        in_specs = (P(None, None), P(None, axis))
-        out_spec = P(None, axis)
-
-        def f(xl, wl):
-            return kops.matmul(xl, wl, backend=backend)
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_spec)(x, w)

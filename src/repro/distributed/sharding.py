@@ -31,15 +31,23 @@ BATCH_AXES = ("pod", "data")
 SEQ_AXES = ("pod", "data", "model")    # KV-seq fallback for tiny batches
 
 
+# Logical axes split over the tensor-parallel ("model") mesh axis.  No
+# config changes them: FSDP only adds "data" rules (rules_for).
+TP_RULES: Dict[str, Any] = {
+    "vocab": "model",
+    "heads": "model",
+    "kv_heads": "model",
+    "mlp": "model",
+    "experts": "model",
+    "ssm_inner": "model",
+    "ssm_heads": "model",
+    "expert_mlp": "model",
+}
+
+
 def rules_for(cfg: ModelConfig) -> Dict[str, Any]:
     return {
-        "vocab": "model",
-        "heads": "model",
-        "kv_heads": "model",
-        "mlp": "model",
-        "experts": "model",
-        "ssm_inner": "model",
-        "ssm_heads": "model",
+        **TP_RULES,
         "state": None,
         "embed": "data" if cfg.fsdp else None,
         "embed_novar": None,          # embed/lm_head d_model: never FSDP
@@ -49,7 +57,6 @@ def rules_for(cfg: ModelConfig) -> Dict[str, Any]:
         # mixtral expert weights (OOM).  The real fix is a dedicated EP
         # mesh axis + all-to-all dispatch (designed, not yet implemented).
         "expert_embed": "data" if cfg.fsdp else None,
-        "expert_mlp": "model",
         "layers": None,
         "experts_in": None,
     }
@@ -74,6 +81,15 @@ def spec_for(shape: Sequence[int], axes: Optional[Sequence[Optional[str]]],
         else:
             parts.append(None)
     return P(*parts)
+
+
+def model_dims(shape: Sequence[int],
+               axes: Optional[Sequence[Optional[str]]],
+               mesh: Mesh) -> Tuple[Optional[str], ...]:
+    """Per dim of a weight: "model" where ``param_shardings`` splits it over
+    the tensor-parallel axis, else None.  The rules that only place "data"
+    take no mesh axis the TP rules want, so TP_RULES alone decide this."""
+    return tuple(spec_for(shape, axes, TP_RULES, mesh))
 
 
 def _named(mesh: Mesh, spec: P) -> NamedSharding:

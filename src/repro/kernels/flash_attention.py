@@ -25,6 +25,7 @@ from repro.core.dtypes import DTYPE_BYTES
 from repro.core.hardware import TPU_V5E
 from repro.core.topology import HardwareSpec
 from repro.core.latency import cdiv
+from repro.kernels.matmul import no_vjp
 
 _NEG_INF = float("-inf")
 _LANES = 128
@@ -163,7 +164,7 @@ def flash_attention_pallas(
         block_q=block_q, block_kv=block_kv, q_len=q_len, kv_len=kv_len,
         out_dtype=q.dtype)
 
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(B, H, Tq, Tkv),
         in_specs=[
@@ -183,4 +184,5 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, d), jnp.float32),        # accumulator
         ],
         interpret=interpret,
-    )(q, k, v)
+    )
+    return no_vjp(call, "flash attention")(q, k, v)

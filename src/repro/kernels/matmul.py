@@ -35,7 +35,7 @@ Inputs must be pre-padded to block multiples — ``ops.matmul`` does this.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.latency import EPILOGUE_NONE, Epilogue, TileConfig, cdiv
+
+
+def no_vjp(fn: Callable, what: str) -> Callable:
+    """``fn`` (a kernel launch over array arguments) with a VJP that refuses.
+
+    The Pallas kernels have no backward rule.  Left alone, Pallas's own JVP
+    rule fails with a bare ``AssertionError`` — which a caller's fallback
+    can mistake for a launch failure and answer with the reference kernel.
+    Differentiating through a kernel must fail loudly instead."""
+    f = jax.custom_vjp(fn)
+
+    def fwd(*args):
+        return fn(*args), None
+
+    def bwd(_res, _g):
+        raise NotImplementedError(
+            f"no VJP for the Pallas {what}; use backend='reference' to train")
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
 def _swizzle(pid, Tm: int, Tn: int, group_m: int):
@@ -178,7 +198,7 @@ def matmul_pallas(
         in_specs.append(pl.BlockSpec((bm, bn), out_index))
 
     kernel = _make_kernel(ep, n_sk=sk, n_k=Tk, out_dtype=out_dtype)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(Tm * Tn, sk, Tk),
         in_specs=in_specs,
@@ -186,4 +206,5 @@ def matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(*inputs)
+    )
+    return no_vjp(call, "GEMM")(*inputs)

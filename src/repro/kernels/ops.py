@@ -20,7 +20,10 @@ deterministic fallback ladder — next-ranked candidate, conservative safe
 config, reference kernel — each transient-retried and each downgrade
 reported through the selection hooks as a ``fallback:<rung>`` source.
 Explicitly-passed ``config`` objects are the caller's contract and never
-silently swapped: they get the transient retry but not the ladder.
+silently swapped: they get the transient retry but not the ladder.  The
+ladder answers launch failures only: a tracing or transformation error
+(``TypeError``, ``NotImplementedError`` — e.g. differentiating a kernel,
+which has no VJP) would fail on every rung alike and propagates.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dtypes import DTYPE_BYTES
-from repro.core.hardware import TPU_V5E
+from repro.core.hardware import TPU_V5E, preset_for_device_kind
 from repro.core.topology import DegradedModeWarning, HardwareSpec
 from repro.core.latency import EPILOGUE_NONE, Epilogue, TileConfig, cdiv
 from repro.core.selector import (Selection, emit_fallback, fallback_ladder,
@@ -74,7 +77,9 @@ def get_backend() -> str:
 # Default serving hardware.  Call sites that don't pass ``hw`` price their
 # selections against this topology; ``launch/serve.py`` points it at a
 # calibrated-topology artifact (or its stock-preset fallback when the
-# artifact was quarantined).  ``None`` -> the tpu_v5e preset.
+# artifact was quarantined).  ``None`` -> the preset of the attached TPU
+# (an unknown device kind is an error, never another chip's peaks); off-TPU
+# the tpu_v5e preset, the chip selections made on a CPU host are for.
 # ---------------------------------------------------------------------------
 
 _hw_override: Optional[HardwareSpec] = None
@@ -87,7 +92,11 @@ def set_default_hardware(hw: Optional[HardwareSpec]) -> None:
 
 
 def get_default_hardware() -> HardwareSpec:
-    return _hw_override if _hw_override is not None else TPU_V5E
+    if _hw_override is not None:
+        return _hw_override
+    if jax.default_backend() == "tpu":
+        return preset_for_device_kind(jax.devices()[0].device_kind)
+    return TPU_V5E
 
 
 # ---------------------------------------------------------------------------
@@ -111,6 +120,10 @@ def set_launch_fault_injector(
     return prev
 
 
+# Errors that say the traced program is wrong, not that a tile config
+# failed to launch: no fallback rung can fix them, so the ladder re-raises.
+_PROGRAM_ERRORS = (TypeError, NotImplementedError)
+
 # Transient-retry policy for kernel launches: short, capped backoff — a
 # launch retry protects against injected/driver transients, not outages.
 _LAUNCH_RETRIES = 2
@@ -129,7 +142,7 @@ def _pad2(x: jax.Array, m: int, n: int) -> jax.Array:
     return x
 
 
-def _normalize_epilogue(
+def normalize_epilogue(
     epilogue: Optional[Union[str, Epilogue]],
     bias, gate, residual,
 ) -> Epilogue:
@@ -187,7 +200,8 @@ def matmul(
     The analytical selection uses the *local* (per-shard) static shapes and
     the fused epilogue traffic, so calling this under shard_map gives
     per-chip-optimal tiles — the intended deployment (see
-    distributed.collectives.tp_matmul).
+    ``nn.layers.dense``, which puts that boundary around every layer GEMM
+    when a mesh is installed).
 
     ``config`` (and selections made against multi-core topologies) may
     carry ``TileConfig.schedule``: ``"data_parallel"`` or ``"stream_k"``.
@@ -199,7 +213,7 @@ def matmul(
     be = backend or get_backend()
     hw = hw if hw is not None else get_default_hardware()
     out_dtype = out_dtype or a.dtype
-    ep = _normalize_epilogue(epilogue, bias, gate, residual)
+    ep = normalize_epilogue(epilogue, bias, gate, residual)
     lead = a.shape[:-2] if a.ndim > 2 else ()
     M = 1
     for s in (*lead, a.shape[-2]):
@@ -271,6 +285,8 @@ def matmul(
     if reason is None:
         try:
             return _try(config)
+        except _PROGRAM_ERRORS:
+            raise
         except Exception as e:                      # noqa: BLE001
             first_err = e
             reason = f"launch failed: {e!r}"
@@ -289,6 +305,8 @@ def matmul(
         emit_fallback(sel_f, rung)
         try:
             return _try(sel_f.config)
+        except _PROGRAM_ERRORS:
+            raise
         except Exception as e:                      # noqa: BLE001
             first_err = first_err or e
             continue
@@ -328,7 +346,7 @@ def expert_matmul(
     be = backend or get_backend()
     hw = hw if hw is not None else get_default_hardware()
     out_dtype = out_dtype or x.dtype
-    ep = _normalize_epilogue(epilogue, bias, gate, residual)
+    ep = normalize_epilogue(epilogue, bias, gate, residual)
 
     if be == "reference":
         acc = jnp.einsum("emk,ekn->emn", x, w,

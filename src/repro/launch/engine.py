@@ -36,6 +36,7 @@ device-step time alongside the host dispatch time.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
@@ -44,12 +45,15 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import meshctx
 from repro.core.bucketing import BucketPlan, step_gemms
 from repro.core.selector import (get_residual_corrector,
                                  select_gemm_config_batch)
 from repro.core.simulator import simulate_gemm
 from repro.core.topology import topology_fingerprint
+from repro.distributed.sharding import cache_shardings
 from repro.kernels import ops
 from repro.nn.model import Model
 from repro.obs import metrics as obs_metrics
@@ -102,7 +106,16 @@ class ServingEngine:
     ``plan`` (optional) buckets ragged prompt lengths; without it every
     distinct length prefills at its exact shape.  ``decode_fault`` is the
     fault-injection hook: called as ``decode_fault(step, guard)`` at the
-    top of every decode attempt, before the cache is donated."""
+    top of every decode attempt, before the cache is donated.
+
+    ``mesh`` (optional) is the mesh ``params`` are sharded over: the decode
+    cache is laid out by ``cache_shardings`` and kept there across steps,
+    and the mesh is installed (``repro.meshctx``) while the engine traces
+    and runs, which puts the kernels under their shard_map boundary.
+
+    The kernel backend is read when a program is traced, and jit's trace
+    cache does not key on it, so each engine jits closures of its own: an
+    engine built under another backend never reuses this one's traces."""
 
     def __init__(self, model: Model, params: Dict, *,
                  max_batch: int, max_len: int,
@@ -111,7 +124,7 @@ class ServingEngine:
                  sync_every: int = 8,
                  decode_fault: Optional[Callable[..., None]] = None,
                  straggler_window: int = 16, straggler_min_steps: int = 4,
-                 quiet: bool = False):
+                 quiet: bool = False, mesh: Optional[Mesh] = None):
         cfg = model.cfg
         if plan is not None and cfg.family in ("ssm", "hybrid"):
             raise ValueError(
@@ -143,8 +156,19 @@ class ServingEngine:
         # monitor's prediction for each sync window); filled by warm_start.
         self.predicted_step_s: Optional[float] = None
 
-        self._prefill = jax.jit(model.prefill)
-        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        self.mesh = mesh
+        B, S = self.max_batch, self.max_len
+        cache_sh = rep = None               # None: sharding left to XLA
+        if mesh is not None:
+            cache_sh = cache_shardings(model.cache_specs(B, S), mesh, cfg)
+            rep = NamedSharding(mesh, P())
+        self._tokens_sharding = rep
+        self._init_cache = jax.jit(lambda: model.init_cache(B, S),
+                                   out_shardings=cache_sh)
+        self._prefill = jax.jit(lambda *a: model.prefill(*a))
+        self._decode = jax.jit(lambda *a: model.decode_step(*a),
+                               donate_argnums=(1,),
+                               out_shardings=(rep, cache_sh))
         if self.temperature > 0:
             t = self.temperature
 
@@ -161,7 +185,8 @@ class ServingEngine:
                 return jax.lax.dynamic_update_slice(
                     dst, src.astype(dst.dtype), start)
             return jax.tree_util.tree_map(one, full, part)
-        self._insert = jax.jit(_insert, donate_argnums=(0,))
+        self._insert = jax.jit(_insert, donate_argnums=(0,),
+                               out_shardings=cache_sh)
 
     # -- queue -------------------------------------------------------------
 
@@ -257,14 +282,86 @@ class ServingEngine:
         self._status(f"transient fault absorbed "
                      f"(attempt {attempt + 1}): {err!r}")
 
+    def _mesh_scope(self):
+        return (meshctx.use_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
+
+    def _padded(self, prompt: np.ndarray
+                ) -> Tuple[jax.Array, Optional[jax.Array], int]:
+        """A prompt right-padded to its bucket edge: (tokens (1, padded),
+        last_pos (None when unpadded), padded length)."""
+        plen = int(prompt.size)
+        padded = (self.plan.bucket_for(plen) if self.plan else plen)
+        toks = np.zeros((1, padded), np.int32)
+        toks[0, :plen] = prompt
+        last_pos = (jnp.asarray([plen - 1], jnp.int32)
+                    if padded != plen else None)
+        return jnp.asarray(toks), last_pos, padded
+
+    def _fresh_tokens(self) -> jax.Array:
+        tokens = jnp.zeros((self.max_batch,), jnp.int32)
+        if self._tokens_sharding is not None:
+            tokens = jax.device_put(tokens, self._tokens_sharding)
+        return tokens
+
+    def probe(self, prompts, next_tokens=None
+              ) -> Tuple[np.ndarray, np.ndarray]:
+        """Prefill ``prompts`` (at most ``max_batch``) into slots 0..n-1 of
+        a fresh cache, then run one decode step over all slots, each at its
+        own position, feeding ``next_tokens`` (default: each prompt's greedy
+        token).  Returns (prefill logits (n, V), decode logits (n, V)) as
+        float32.  It runs the engine's own programs at the shapes it serves
+        (each prompt padded to its bucket), so at served lengths it compiles
+        nothing new: the way to hold one backend against another on the
+        same weights."""
+        prompts = [np.asarray(p, np.int32).reshape(-1) for p in prompts]
+        n = len(prompts)
+        if not 0 < n <= self.max_batch:
+            raise ValueError(f"probe takes 1..{self.max_batch} prompts, "
+                             f"got {n}")
+        pos = np.zeros((self.max_batch,), np.int32)
+        with self._mesh_scope():
+            cache = self._init_cache()
+            first = []
+            for b, prompt in enumerate(prompts):
+                toks, last_pos, _ = self._padded(prompt)
+                logits, pc = self._prefill(self.params, toks, None, last_pos)
+                cache = self._insert(cache, pc, jnp.int32(b))
+                first.append(logits[0])
+                pos[b] = prompt.size
+            first = jnp.stack(first)
+            nxt = (jnp.argmax(first, axis=-1) if next_tokens is None
+                   else jnp.asarray(next_tokens)).astype(jnp.int32)
+            tokens = self._fresh_tokens().at[:n].set(nxt)
+            dec, _ = self._decode(self.params, cache, tokens,
+                                  jnp.asarray(pos))
+        return (np.asarray(first, np.float32),
+                np.asarray(dec[:n], np.float32))
+
+    def lower_decode(self):
+        """The decode step lowered at the engine's serving shapes (nothing
+        runs or compiles) — for inspecting what the step launches."""
+        with self._mesh_scope():
+            B = self.max_batch
+            tokens = jax.ShapeDtypeStruct((B,), jnp.int32,
+                                          sharding=self._tokens_sharding)
+            pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+            return self._decode.lower(self.params,
+                                      jax.eval_shape(self._init_cache),
+                                      tokens, pos)
+
     def run(self) -> Dict:
         """Serve the queue to completion (or preemption drain); returns the
         stats dict (see DESIGN.md §10 for the schema)."""
+        with self._mesh_scope():
+            return self._run()
+
+    def _run(self) -> Dict:
         cfg = self.model.cfg
         B = self.max_batch
         slots = [_Slot() for _ in range(B)]
-        cache = self.model.init_cache(B, self.max_len)
-        tokens = jnp.zeros((B,), jnp.int32)
+        cache = self._init_cache()
+        tokens = self._fresh_tokens()
         pos_host = [0] * B
         tok_log: List[jax.Array] = []        # per-step (B,) device arrays
         owners: List[Tuple[int, ...]] = []   # per-step slot->rid snapshot
@@ -294,18 +391,14 @@ class ServingEngine:
             nonlocal cache
             req = self._queue.pop(0)
             plen = int(req.prompt.size)
-            padded = (self.plan.bucket_for(plen) if self.plan else plen)
-            prompt = np.zeros((1, padded), np.int32)
-            prompt[0, :plen] = req.prompt
-            last_pos = (jnp.asarray([plen - 1], jnp.int32)
-                        if padded != plen else None)
+            prompt, last_pos, padded = self._padded(req.prompt)
             t0 = time.perf_counter()
             with (tr.span("prefill", cat="engine", track="engine",
                           args={"rid": req.rid, "slot": b,
                                 "prompt_len": plen, "padded_len": padded})
                   if tr is not None else obs_trace.NULL_SPAN):
                 logits, pc = retry(
-                    lambda: self._prefill(self.params, jnp.asarray(prompt),
+                    lambda: self._prefill(self.params, prompt,
                                           req.extras or None, last_pos),
                     retries=_STEP_RETRIES, base_delay=_STEP_BASE_DELAY,
                     max_delay=_STEP_MAX_DELAY, on_retry=self._count_retry)

@@ -45,7 +45,6 @@ import jax
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.bucketing import plan_buckets, step_gemms
-from repro.core.hardware import TPU_V5E
 from repro.core.selector import (get_residual_corrector,
                                  load_selection_cache, select_gemm_config,
                                  set_residual_corrector)
@@ -53,6 +52,7 @@ from repro.core.simulator import simulate_gemm
 from repro.core.topology import load_calibrated_topology_guarded
 from repro.distributed import param_shardings
 from repro.kernels import ops
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.engine import ServingEngine
 from repro.launch.mesh import make_local_mesh
 from repro.nn.frontends import synth_frontend_inputs
@@ -121,8 +121,10 @@ def run_serving(args: argparse.Namespace, *,
     stopped decode early), ``steps`` (decode steps completed), ``retries``
     (transient retries absorbed), ``stragglers``, timings, engine stats
     (``pad_fraction``, ``bucket_hits``, ``dispatch_s_mean``,
-    ``device_step_s_mean``, ``tokens_per_s``), and the topology served
-    against (plus ``degraded`` when the artifact was rejected).
+    ``device_step_s_mean``, ``tokens_per_s``), the topology served
+    against (plus ``degraded`` when the artifact was rejected), the
+    request ``prompts`` in rid order, and the ``engine`` itself (its
+    programs stay compiled for probes and reruns).
 
     ``--quiet`` suppresses the stdout status lines (they still flow
     through the trace layer as events); ``--trace-dir DIR`` installs the
@@ -202,9 +204,10 @@ def _run_serving(args: argparse.Namespace, *,
     if n_warm:
         say(f"[selector] warm-started {n_warm} persisted GEMM selections")
 
-    topo_info: Dict = {"topology": TPU_V5E.name, "degraded": None}
+    stock = ops.get_default_hardware()
+    topo_info: Dict = {"topology": stock.name, "degraded": None}
     if getattr(args, "topology", None):
-        topo, prov = load_calibrated_topology_guarded(args.topology, TPU_V5E)
+        topo, prov = load_calibrated_topology_guarded(args.topology, stock)
         ops.set_default_hardware(topo)
         topo_info = {"topology": topo.name,
                      "degraded": prov.get("degraded"),
@@ -279,7 +282,8 @@ def _run_serving(args: argparse.Namespace, *,
         temperature=args.temperature, seed=args.seed,
         sync_every=getattr(args, "sync_every", 8),
         decode_fault=decode_fault,
-        straggler_window=16, straggler_min_steps=4, quiet=quiet)
+        straggler_window=16, straggler_min_steps=4, quiet=quiet,
+        mesh=mesh)
 
     def _extras(i):
         if not extras:
@@ -337,6 +341,8 @@ def _run_serving(args: argparse.Namespace, *,
         "device_step_s_mean": stats["device_step_s_mean"],
         "residual_active": stats["residual_active"],
         "results": results,
+        "prompts": [prompts[i, :lens[i]] for i in range(n_req)],
+        "engine": engine,
         **topo_info,
         **res_info,
     }
@@ -344,6 +350,7 @@ def _run_serving(args: argparse.Namespace, *,
 
 def main() -> int:
     args = build_parser().parse_args()
+    setup_compile_cache()
     run_serving(args)
     return 0
 

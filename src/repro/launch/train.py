@@ -29,6 +29,7 @@ from repro.configs.registry import ARCH_IDS, get_config
 from repro.data import DataConfig, Prefetcher, SyntheticLM
 from repro.distributed import (batch_shardings, opt_shardings,
                                param_shardings, replicated)
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import TrainState, make_train_step
 from repro.nn.frontends import synth_frontend_inputs
@@ -54,6 +55,7 @@ def main() -> int:
     ap.add_argument("--log", default=None, help="JSONL metrics path")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = Model(cfg)

@@ -4,11 +4,13 @@ GSPMD propagates input/param shardings well, but the remat layer stash is
 shaped by the scan-body *boundary* layout.  ``constrain`` lets model code
 pin activations (e.g. sequence-sharded residual stream — Megatron-style SP)
 when a mesh is installed; it is a no-op otherwise, so models stay runnable
-on bare CPU.
+on bare CPU.  The installed mesh also places the ``shard_map`` boundary that
+``nn.layers`` puts around the Pallas kernels, which GSPMD cannot partition.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import contextlib
+from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
@@ -25,6 +27,17 @@ def set_mesh(mesh: Optional[Mesh]) -> None:
 
 def get_mesh() -> Optional[Mesh]:
     return _MESH
+
+
+@contextlib.contextmanager
+def use_mesh(mesh: Optional[Mesh]) -> Iterator[None]:
+    """Install ``mesh`` for a with-block; the previous mesh comes back."""
+    prev = _MESH
+    set_mesh(mesh)
+    try:
+        yield
+    finally:
+        set_mesh(prev)
 
 
 def constrain(x: jax.Array, *parts) -> jax.Array:

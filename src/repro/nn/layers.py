@@ -10,17 +10,23 @@ def tree yields:
   * ``axes_tree``      — logical axes (sharding rules)
 
 Dense contractions go through ``repro.kernels.ops.matmul`` — the tritonBLAS
-selector chooses the kernel tiling at trace time (zero autotuning).
+selector chooses the kernel tiling at trace time (zero autotuning).  GSPMD
+cannot partition a Pallas kernel, so when a mesh of several devices is
+installed (``repro.meshctx``) the kernel calls run under ``shard_map`` on
+per-chip shapes: the GEMMs by the weight's layout, attention over heads.
 """
 from __future__ import annotations
 
-import functools
+import math
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import meshctx
 from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 from repro.nn import attention as attn_lib
 from repro.nn.config import ModelConfig
 
@@ -105,17 +111,92 @@ def norm_defs(cfg: ModelConfig) -> Dict:
     return d
 
 
-def dense(x: jax.Array, w: jax.Array, out_dtype=None, *,
+def _kernel_mesh() -> Optional[Mesh]:
+    """The installed mesh when kernel calls need a shard_map boundary: a
+    Pallas backend on more than one device.  The reference backend is plain
+    jnp, which GSPMD partitions by itself."""
+    mesh = meshctx.get_mesh()
+    if mesh is None or mesh.size == 1 or kops.get_backend() == "reference":
+        return None
+    return mesh
+
+
+def _batch_axes(mesh: Mesh, dim: int):
+    """Mesh axes for an activation's leading (batch) dim: the data axes,
+    when they divide it; otherwise None (replicated)."""
+    axes = tuple(a for a in ("pod", "data") if mesh.shape.get(a, 1) > 1)
+    if axes and dim % math.prod(mesh.shape[a] for a in axes) == 0:
+        return axes
+    return None
+
+
+def dense(x: jax.Array, w: jax.Array, out_dtype=None, *, axes=None,
           epilogue=None, bias=None, gate=None,
           residual=None) -> jax.Array:
     """Selector-driven fused GEMM: epilogue(x (..., K) @ w (K, N)).
 
     The epilogue (bias / gelu / silu / swiglu-gate / residual) executes
     inside the kernel's flush step — one HBM round trip per layer instead of
-    one per post-op (DESIGN.md §3)."""
-    return kops.matmul(x, w, out_dtype=out_dtype or x.dtype,
-                       epilogue=epilogue, bias=bias, gate=gate,
-                       residual=residual)
+    one per post-op (DESIGN.md §3).
+
+    ``axes`` is the weight's logical axes (its ParamDef's).  Under a mesh
+    the kernel sees the weight as ``param_shardings`` lays it out over the
+    "model" axis: N split (the output stays split on N), K split (partial
+    products are psum'd in f32 and the epilogue runs after the sum), or
+    neither (replicated, as for ``axes=None``)."""
+    out_dtype = out_dtype or x.dtype
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return kops.matmul(x, w, out_dtype=out_dtype, epilogue=epilogue,
+                           bias=bias, gate=gate, residual=residual)
+    # Imported here: repro.distributed imports the model, which imports this.
+    from repro.distributed.sharding import model_dims
+    k_ax, n_ax = model_dims(w.shape, axes, mesh)
+    mid = (None,) * (x.ndim - 2)
+    lead = _batch_axes(mesh, x.shape[0])
+    out_spec = P(lead, *mid, n_ax)
+    ep = kops.normalize_epilogue(epilogue, bias, gate, residual)
+    extra = {name: (val, spec) for name, val, spec in (
+        ("bias", bias, P(n_ax)), ("gate", gate, out_spec),
+        ("residual", residual, out_spec)) if val is not None}
+
+    def local(xl, wl, *ex):
+        kw = dict(zip(extra, ex))
+        if k_ax is None:
+            return kops.matmul(xl, wl, out_dtype=out_dtype, epilogue=ep,
+                               **kw)
+        y = jax.lax.psum(kops.matmul(xl, wl, out_dtype=jnp.float32), k_ax)
+        return kref.apply_epilogue_ref(y, ep, **kw).astype(out_dtype)
+
+    # check_vma=False: a pallas_call's out_shape carries no varying-axes
+    # annotation, so the checker would refuse the kernel.
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(lead, *mid, k_ax), P(k_ax, n_ax),
+                  *(spec for _, spec in extra.values())),
+        out_specs=out_spec, check_vma=False,
+    )(x, w, *(val for val, _ in extra.values()))
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal attention through the Pallas flash kernel; under an installed
+    mesh it runs per chip over heads (kv heads repeated to the q heads when
+    the "model" axis does not divide them)."""
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return kops.flash_attention(q, k, v, causal=True)
+    tp = mesh.shape.get("model", 1)
+    H, Hkv = q.shape[1], k.shape[1]
+    heads = "model" if H % tp == 0 else None
+    if heads and Hkv % tp:
+        k = jnp.repeat(k, H // Hkv, axis=1)
+        v = jnp.repeat(v, H // Hkv, axis=1)
+    spec = P(_batch_axes(mesh, q.shape[0]), heads, None, None)
+    return jax.shard_map(
+        lambda q, k, v: kops.flash_attention(q, k, v, causal=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -174,26 +255,32 @@ def attn_forward(
 ) -> jax.Array:
     B, S, D = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    ax = axes_tree(attn_defs(cfg))
     h = norm(x, p["norm"], cfg)
-    q = dense(h, p["wq"]).reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+    q = dense(h, p["wq"], axes=ax["wq"]).reshape(B, S, H, hd) \
+        .transpose(0, 2, 1, 3)
     group = H // Hkv
     if cfg.kv_repeat_weights and group > 1:
         wk = _repeat_kv_weight(p["wk"], Hkv, hd, group)
         wv = _repeat_kv_weight(p["wv"], Hkv, hd, group)
-        k = dense(h, wk).reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-        v = dense(h, wv).reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+        k = dense(h, wk, axes=ax["wk"]).reshape(B, S, H, hd) \
+            .transpose(0, 2, 1, 3)
+        v = dense(h, wv, axes=ax["wv"]).reshape(B, S, H, hd) \
+            .transpose(0, 2, 1, 3)
     else:
-        k = dense(h, p["wk"]).reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
-        v = dense(h, p["wv"]).reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
+        k = dense(h, p["wk"], axes=ax["wk"]).reshape(B, S, Hkv, hd) \
+            .transpose(0, 2, 1, 3)
+        v = dense(h, p["wv"], axes=ax["wv"]).reshape(B, S, Hkv, hd) \
+            .transpose(0, 2, 1, 3)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     if kops.get_backend() == "pallas" and cfg.sliding_window == 0:
-        out = kops.flash_attention(q, k, v, causal=True)
+        out = flash_attention(q, k, v)
     else:
         out = attn_lib.chunked_attention(
             q, k, v, causal=True, sliding_window=cfg.sliding_window)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-    return dense(out, p["wo"], residual=residual)
+    return dense(out, p["wo"], axes=ax["wo"], residual=residual)
 
 
 def attn_decode(
@@ -206,10 +293,14 @@ def attn_decode(
 ) -> Tuple[jax.Array, Dict]:
     B, _, D = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    ax = axes_tree(attn_defs(cfg))
     h = norm(x, p["norm"], cfg)
-    q = dense(h, p["wq"]).reshape(B, 1, H, hd).transpose(0, 2, 1, 3)
-    k = dense(h, p["wk"]).reshape(B, 1, Hkv, hd).transpose(0, 2, 1, 3)
-    v = dense(h, p["wv"]).reshape(B, 1, Hkv, hd).transpose(0, 2, 1, 3)
+    q = dense(h, p["wq"], axes=ax["wq"]).reshape(B, 1, H, hd) \
+        .transpose(0, 2, 1, 3)
+    k = dense(h, p["wk"], axes=ax["wk"]).reshape(B, 1, Hkv, hd) \
+        .transpose(0, 2, 1, 3)
+    v = dense(h, p["wv"], axes=ax["wv"]).reshape(B, 1, Hkv, hd) \
+        .transpose(0, 2, 1, 3)
     if jnp.ndim(pos) == 0:
         posv = jnp.reshape(pos, (1,))
         q = rope(q, posv, cfg.rope_theta)
@@ -232,7 +323,8 @@ def attn_decode(
         q, k_cache, v_cache, pos=pos, sliding_window=cfg.sliding_window,
         gqa_packed=cfg.gqa_packed_decode)
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd)
-    return dense(out, p["wo"]), {"k": k_cache, "v": v_cache}
+    return (dense(out, p["wo"], axes=ax["wo"]),
+            {"k": k_cache, "v": v_cache})
 
 
 # ---------------------------------------------------------------------------
@@ -260,10 +352,12 @@ def mlp_forward(p: Dict, x: jax.Array, cfg: ModelConfig,
     """Fused MLP: activations run in the GEMM epilogues, never as separate
     XLA elementwise passes; the block's residual add (when given) fuses into
     the down-projection's flush."""
+    ax = axes_tree(mlp_defs(cfg))
     h = norm(x, p["norm"], cfg)
     if cfg.activation == "swiglu":
-        u = dense(h, p["wu"])
-        a = dense(h, p["wg"], epilogue="swiglu_gate", gate=u)
-        return dense(a, p["wd"], residual=residual)
-    h1 = dense(h, p["w1"], epilogue="gelu")
-    return dense(h1, p["w2"], residual=residual)
+        u = dense(h, p["wu"], axes=ax["wu"])
+        a = dense(h, p["wg"], axes=ax["wg"], epilogue="swiglu_gate",
+                  gate=u)
+        return dense(a, p["wd"], axes=ax["wd"], residual=residual)
+    h1 = dense(h, p["w1"], axes=ax["w1"], epilogue="gelu")
+    return dense(h1, p["w2"], axes=ax["w2"], residual=residual)

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from repro.nn import scanning
 from repro.nn.config import ModelConfig
-from repro.nn.layers import ParamDef, dense, norm, norm_defs, rmsnorm
+from repro.nn.layers import (ParamDef, axes_tree, dense, norm, norm_defs,
+                             rmsnorm)
 
 NEG_INF = float("-inf")
 
@@ -122,11 +123,12 @@ def mamba_defs(cfg: ModelConfig) -> Dict:
 
 def _project(p: Dict, h: jax.Array, cfg: ModelConfig):
     """h -> (z, x, B, C, dt) via the five separate projections."""
-    z = dense(h, p["in_z"])
-    xs = dense(h, p["in_x"])
-    Bm = dense(h, p["in_b"])
-    Cm = dense(h, p["in_c"])
-    dt = dense(h, p["in_dt"])
+    ax = axes_tree(mamba_defs(cfg))
+    z = dense(h, p["in_z"], axes=ax["in_z"])
+    xs = dense(h, p["in_x"], axes=ax["in_x"])
+    Bm = dense(h, p["in_b"], axes=ax["in_b"])
+    Cm = dense(h, p["in_c"], axes=ax["in_c"])
+    dt = dense(h, p["in_dt"], axes=ax["in_dt"])
     return z, xs, Bm, Cm, dt
 
 
@@ -181,7 +183,7 @@ def mamba_forward(p: Dict, x: jax.Array, cfg: ModelConfig,
     y = y + p["D"].astype(y.dtype)[None, None, :, None] * xh[:, :S]
     y = y.reshape(B, S, di)
     y = rmsnorm(y * jax.nn.silu(z), p["gate_norm"])
-    out = dense(y, p["out_proj"])
+    out = dense(y, p["out_proj"], axes=mamba_defs(cfg)["out_proj"].axes)
     if return_cache:
         return out, {**conv_tail, "ssm": final_state}
     return out
@@ -232,5 +234,6 @@ def mamba_decode(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig
     y = y + p["D"][None, :, None] * xs.reshape(B, nh, hd).astype(jnp.float32)
     y = y.reshape(B, di).astype(x.dtype)
     y = rmsnorm(y * jax.nn.silu(z), p["gate_norm"])
-    return dense(y, p["out_proj"])[:, None], \
+    out = dense(y, p["out_proj"], axes=mamba_defs(cfg)["out_proj"].axes)
+    return out[:, None], \
         {"conv_x": new_cx, "conv_b": new_cb, "conv_c": new_cc, "ssm": state}

@@ -111,9 +111,12 @@ def lm_head_weight(params: Dict, cfg: ModelConfig) -> jax.Array:
 def _kv_for_cache(attn_p, h, positions, cfg):
     B, S, _ = h.shape
     Hkv, hd = cfg.num_kv_heads, cfg.head_dim
+    ax = L.axes_tree(L.attn_defs(cfg))
     hn = L.norm(h, attn_p["norm"], cfg)
-    k = L.dense(hn, attn_p["wk"]).reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
-    v = L.dense(hn, attn_p["wv"]).reshape(B, S, Hkv, hd).transpose(0, 2, 1, 3)
+    k = L.dense(hn, attn_p["wk"], axes=ax["wk"]).reshape(B, S, Hkv, hd) \
+        .transpose(0, 2, 1, 3)
+    v = L.dense(hn, attn_p["wv"], axes=ax["wv"]).reshape(B, S, Hkv, hd) \
+        .transpose(0, 2, 1, 3)
     k = L.rope(k, positions, cfg.rope_theta)
     return {"k": k, "v": v}
 

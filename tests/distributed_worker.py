@@ -1,8 +1,9 @@
 """Worker script run in a subprocess with 8 fake CPU devices.
 
 Each check exercises the distribution layer on a real (2, 4) mesh:
-sharded train steps, tp_matmul via shard_map, compressed DP psum, elastic
-checkpoint restore onto a different mesh shape.  Invoked by
+sharded train steps, the kernels' shard_map boundary in nn.layers, serving
+at tp=4, compressed DP psum, elastic checkpoint restore onto a different
+mesh shape.  Invoked by
 tests/test_distributed.py; prints CHECK_OK markers the test asserts on.
 """
 import os
@@ -17,20 +18,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.5 exports it at top level
-    shard_map = jax.shard_map
-    _NO_REPCHECK = {"check_vma": False}
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-    _NO_REPCHECK = {"check_rep": False}   # pre-0.5 spelling
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.registry import get_config            # noqa: E402
 from repro.distributed import (batch_shardings,           # noqa: E402
                                opt_shardings, param_shardings, replicated,
-                               spec_for, rules_for, tp_matmul)
+                               spec_for, rules_for)
+from repro.kernels import ref, set_backend                # noqa: E402
 from repro.launch.mesh import make_local_mesh             # noqa: E402
+from repro.meshctx import use_mesh                        # noqa: E402
+from repro.nn import layers as L                          # noqa: E402
 from repro.launch.steps import (TrainState,               # noqa: E402
                                 make_train_step)
 from repro.nn.model import Model                          # noqa: E402
@@ -73,18 +70,80 @@ def check_sharded_train_step():
     print("CHECK_OK sharded_train_step")
 
 
-def check_tp_matmul():
+def check_tp_dense():
+    """Pallas kernels (interpret mode) under an installed (2, 4) mesh run
+    inside shard_map on per-chip shapes and match the reference: column-
+    and row-sharded GEMMs (the row psum precedes the fused epilogue), a
+    replicated weight, and flash attention over heads, with and without
+    kv heads the model axis divides."""
     mesh = make_local_mesh(tp=4)
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((64, 128)), dtype=jnp.float32)
-    w = jnp.asarray(rng.standard_normal((128, 256)), dtype=jnp.float32)
-    want = np.asarray(x @ w)
-    got = np.asarray(tp_matmul(x, w, mesh, "model", backend="reference"))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
-    got_k = np.asarray(tp_matmul(x, w, mesh, "model", reduce_k=True,
-                                 backend="reference"))
-    np.testing.assert_allclose(got_k, want, rtol=1e-4, atol=1e-3)
-    print("CHECK_OK tp_matmul")
+    x = jnp.asarray(rng.standard_normal((4, 8, 128)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((128, 256)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((4, 8, 256)), jnp.float32)
+    y = np.asarray(x @ w)
+    col, row = ("embed", "mlp"), ("mlp", "embed")
+    cases = [(col, {"residual": r}, y + np.asarray(r)),
+             (row, {"residual": r}, y + np.asarray(r)),
+             (row, {"epilogue": "gelu"}, np.asarray(jax.nn.gelu(x @ w))),
+             (None, {}, y)]
+    set_backend("pallas_interpret")
+    try:
+        with use_mesh(mesh):
+            for axes, kw, want in cases:
+                got = jax.jit(lambda x, w: L.dense(x, w, axes=axes, **kw)
+                              )(x, w)
+                np.testing.assert_allclose(np.asarray(got), want,
+                                           rtol=1e-4, atol=1e-3)
+            hlo = jax.jit(lambda x, w: L.dense(x, w, axes=row)) \
+                .lower(x, w).compile().as_text()
+            assert "all-reduce" in hlo
+            for hkv in (4, 2):
+                q = jnp.asarray(rng.standard_normal((2, 8, 128, 64)),
+                                jnp.float32)
+                k, v = (jnp.asarray(rng.standard_normal((2, hkv, 128, 64)),
+                                    jnp.float32) for _ in range(2))
+                got = jax.jit(L.flash_attention)(q, k, v)
+                want = ref.attention_ref(q, k, v, causal=True)
+                np.testing.assert_allclose(np.asarray(got),
+                                           np.asarray(want),
+                                           rtol=1e-4, atol=1e-4)
+    finally:
+        set_backend(None)
+    print("CHECK_OK tp_dense")
+
+
+def check_tp_serving():
+    """run_serving at tp=4 on the (2, 4) mesh with interpret-mode kernels:
+    the engine keeps its cache on the mesh, every request completes, and
+    probe logits match the reference backend on the same weights and mesh."""
+    import argparse
+    from repro.launch.engine import ServingEngine
+    from repro.launch.serve import run_serving
+    args = argparse.Namespace(
+        arch="minitron-8b", smoke=True, batch=4, prompt_len=16, gen=4,
+        temperature=0.0, tp=4, seed=0, ragged=True, requests=6, quiet=True)
+    set_backend("pallas_interpret")
+    try:
+        out = run_serving(args)
+        eng = out["engine"]
+        assert dict(eng.mesh.shape) == {"data": 2, "model": 4}
+        assert all(len(r.tokens) == 4 for r in out["results"].values())
+        prompts = out["prompts"][:4]          # one per slot
+        kernel = eng.probe(prompts)
+        set_backend("reference")
+        ref_eng = ServingEngine(eng.model, eng.params, max_batch=4,
+                                max_len=eng.max_len, plan=eng.plan,
+                                mesh=eng.mesh, quiet=True)
+        want = ref_eng.probe(prompts,
+                             next_tokens=np.argmax(kernel[0], axis=-1))
+    finally:
+        set_backend(None)
+    for got, ref_logits in zip(kernel, want):
+        err = (np.max(np.abs(got - ref_logits), axis=-1)
+               / np.max(np.abs(ref_logits), axis=-1))
+        assert err.shape == (4,) and np.all(err < 2e-2), err
+    print("CHECK_OK tp_serving")
 
 
 def check_compressed_psum():
@@ -99,9 +158,9 @@ def check_compressed_psum():
 
     # Replication check off: the all_gather+local-reduce result is replicated
     # by construction, but jax cannot prove invariance across "data".
-    mean, new_err = shard_map(
+    mean, new_err = jax.shard_map(
         f, mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=(P(None), P("data")), **_NO_REPCHECK)(g, err)
+        out_specs=(P(None), P("data")), check_vma=False)(g, err)
     # Each device's row of `mean` is the mean over devices within int8 error.
     want = np.asarray(jnp.mean(g, axis=0))
     got = np.asarray(mean)[0]
@@ -147,7 +206,8 @@ def check_spec_divisibility_drop():
 if __name__ == "__main__":
     assert jax.device_count() == 8, jax.device_count()
     check_spec_divisibility_drop()
-    check_tp_matmul()
+    check_tp_dense()
+    check_tp_serving()
     check_compressed_psum()
     check_elastic_restore()
     check_sharded_train_step()

@@ -389,6 +389,20 @@ def test_explicit_config_never_silently_swapped(injector):
     assert np.asarray(out).shape == (128, 128)
 
 
+@pytest.mark.parametrize("err", [TypeError("traced a bad shape"),
+                                 NotImplementedError("no rule")],
+                         ids=["TypeError", "NotImplementedError"])
+def test_program_error_is_not_a_launch_failure(hooked, injector, err):
+    """A tracing or transformation error would fail on every rung alike:
+    the ladder re-raises it instead of serving the reference kernel."""
+    injector(scripted_injector([err]))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", DegradedModeWarning)
+        with pytest.raises(type(err)):
+            _matmul_vs_reference(TPU_V5E, seed=15)
+    assert not [s for s, _ in hooked if s.startswith("fallback")]
+
+
 def test_poisoned_memo_is_revalidated_before_launch(hooked, injector):
     """A memo entry poisoned into a placement-busting config (a buggy hook,
     a cosmic-ray cache) is caught by pre-launch validation and the ladder

@@ -128,6 +128,15 @@ def test_matmul_epilogue_vs_ref(shape, dt, ep):
     np.testing.assert_allclose(got, want, rtol=rtol, atol=atol)
 
 
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr, nested ones included (the kernel launch
+    sits inside its no-VJP wrapper's sub-jaxpr)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
 @pytest.mark.parametrize("ep", [Epilogue(), Epilogue(activation="gelu"),
                                 Epilogue(activation="swiglu_gate")], ids=str)
 def test_matmul_split_k_in_kernel(ep):
@@ -141,11 +150,11 @@ def test_matmul_split_k_in_kernel(ep):
 
     fn = lambda a, b: matmul(a, b, out_dtype=jnp.float32, config=cfg,
                              epilogue=ep, backend="pallas_interpret", **kw)
-    jaxpr = jax.make_jaxpr(fn)(a, b)
-    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    eqns = list(_all_eqns(jax.make_jaxpr(fn)(a, b).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     sk_shape = (cfg.split_k, M, N)
-    for eqn in jaxpr.jaxpr.eqns:
+    for eqn in eqns:
         for v in eqn.outvars:
             assert tuple(getattr(v.aval, "shape", ())) != sk_shape
 
@@ -290,3 +299,21 @@ def test_decode_attention_matches_prefix():
                                       pos=jnp.int32(S - 1)))
     np.testing.assert_allclose(out[:, :, 0], full[:, :, -1],
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("op", ["matmul", "flash_attention"])
+def test_grad_through_pallas_kernel_raises(op):
+    """The kernels have no VJP: differentiating one fails loudly instead
+    of the launch ladder quietly answering with the reference GEMM."""
+    x = jnp.asarray(RNG.standard_normal((2, 128, 128)), jnp.float32)
+    w = jnp.asarray(RNG.standard_normal((128, 128)), jnp.float32)
+    if op == "matmul":
+        def loss(x):
+            return matmul(x, w, backend="pallas_interpret").sum()
+    else:
+        def loss(x):
+            q = x[None]
+            return flash_attention(q, q, q, causal=True,
+                                   backend="pallas_interpret").sum()
+    with pytest.raises(NotImplementedError, match="no VJP for the Pallas"):
+        jax.grad(loss)(x)

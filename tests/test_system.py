@@ -8,7 +8,8 @@ import sys
 import numpy as np
 import pytest
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SRC = os.path.join(ROOT, "src")
 
 
 def _run(args, timeout=560):
@@ -109,3 +110,35 @@ def test_collective_parser_units():
     assert out["reduce-scatter"] == 16 * 4
     assert out["collective-permute"] == 8 * 4 * 2
     assert out["total"] == sum(v for k, v in out.items() if k != "total")
+
+
+@pytest.mark.timeout(300)
+def test_chip_smoke_refuses_a_host_without_tpu():
+    """chip_smoke.py must fail, and print no ok line, where JAX finds no
+    TPU: no CPU, interpret-mode or reference path may pass for the chip."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=240)
+    assert p.returncode != 0, p.stdout[-2000:] + p.stderr[-2000:]
+    assert '"ok": true' not in p.stdout
+    assert "no TPU" in p.stderr
+
+
+def test_compile_cache_follows_env_else_fixed_checkout_path(tmp_path,
+                                                           monkeypatch):
+    import jax
+    from repro.launch.compile_cache import setup_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev  # left alone
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(ROOT, ".jax_cache")
+        assert setup_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
