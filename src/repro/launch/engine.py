@@ -30,9 +30,16 @@ or drain never shifts the key stream).
 
 The decode loop never round-trips to the host: sampled tokens stay on
 device (one stack at end of run), RNG keys are pre-split in chunks, and
-the loop blocks only at ``sync_every`` boundaries — where the
-:class:`~repro.runtime.fault_tolerance.StragglerMonitor` records the pure
-device-step time alongside the host dispatch time.
+the loop blocks only at ``sync_every`` boundaries, where it stamps the
+host clock (``step_ready_s``: every step's ready time at
+``sync_every=1``).
+
+With a tracer installed (``repro.obs.trace``), ``run()`` records its phases
+as spans on the ``engine`` track (DESIGN.md §11): ``init``, ``admit``,
+``upload``, ``decode_dispatch`` (which holds ``hook``), ``bookkeeping``,
+``sync`` and ``collect``.  A ``Tracer(profiler=True)`` puts them into the
+JAX profiler's trace as ``engine.<name>``.  With none installed each call
+site costs one ``is None`` check and allocates nothing.
 """
 from __future__ import annotations
 
@@ -60,8 +67,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.drift import get_drift_monitor, record_step_drift
 from repro.obs.metrics import MetricsRegistry
-from repro.runtime.fault_tolerance import (PreemptionGuard, StragglerMonitor,
-                                           retry)
+from repro.runtime.fault_tolerance import PreemptionGuard, retry
 
 _STEP_RETRIES = 2
 _STEP_BASE_DELAY = 0.01
@@ -75,6 +81,7 @@ class Request:
     prompt: np.ndarray                  # (len,) int32 token ids
     max_new_tokens: int                 # tokens to emit (incl. prefill's)
     extras: Optional[Dict] = None
+    t_submit: float = 0.0               # host clock at submit()
 
 
 @dataclass
@@ -86,6 +93,8 @@ class RequestResult:
     admit_step: int                     # global step of first decode
     finish_step: int                    # global step after last decode
     finished: bool                      # False when drained mid-flight
+    t_submit: float = 0.0               # host clock (perf_counter): submit
+    t_admit: float = 0.0                # and its pop from the queue
 
 
 @dataclass
@@ -123,7 +132,6 @@ class ServingEngine:
                  temperature: float = 0.0, seed: int = 0,
                  sync_every: int = 8,
                  decode_fault: Optional[Callable[..., None]] = None,
-                 straggler_window: int = 16, straggler_min_steps: int = 4,
                  quiet: bool = False, mesh: Optional[Mesh] = None):
         cfg = model.cfg
         if plan is not None and cfg.family in ("ssm", "hybrid"):
@@ -143,8 +151,6 @@ class ServingEngine:
         self._next_rid = 0
         self._base_key = jax.random.PRNGKey(seed)
         self._key_chunks: Dict[int, jax.Array] = {}
-        self.straggler = StragglerMonitor(window=straggler_window,
-                                          min_steps=straggler_min_steps)
         self.retries = 0
         self.quiet = bool(quiet)
         # Per-run metrics registry (DESIGN.md §11): ``run()`` rebuilds it,
@@ -210,7 +216,8 @@ class ServingEngine:
         self._next_rid += 1
         self._queue.append(Request(rid=rid, prompt=prompt,
                                    max_new_tokens=int(max_new_tokens),
-                                   extras=extras))
+                                   extras=extras,
+                                   t_submit=time.perf_counter()))
         return rid
 
     # -- warm-up -----------------------------------------------------------
@@ -357,16 +364,25 @@ class ServingEngine:
             return self._run()
 
     def _run(self) -> Dict:
-        cfg = self.model.cfg
         B = self.max_batch
+        tr = obs_trace.get_tracer()
+        on = tr is not None            # call sites build span args only then
+
+        def span(name: str, args: Optional[Dict] = None):
+            if tr is None:
+                return obs_trace.NULL_SPAN
+            return tr.span(name, cat="engine", track="engine", args=args)
+
         slots = [_Slot() for _ in range(B)]
-        cache = self._init_cache()
-        tokens = self._fresh_tokens()
+        with span("init"):
+            cache = self._init_cache()
+            tokens = self._fresh_tokens()
         pos_host = [0] * B
         tok_log: List[jax.Array] = []        # per-step (B,) device arrays
         owners: List[Tuple[int, ...]] = []   # per-step slot->rid snapshot
         first_tok: Dict[int, jax.Array] = {}  # rid -> (1,) prefill token
-        meta: Dict[int, Tuple[int, int, int]] = {}  # rid -> (plen,padded,adm)
+        # rid -> (prompt_len, padded_len, admit step, t_submit, t_admit)
+        meta: Dict[int, Tuple[int, int, int, float, float]] = {}
         finished: Dict[int, int] = {}        # rid -> finish_step
         # Per-run metrics registry: the integer stats accumulators ARE
         # registry counters now (same arithmetic, so the public stats dict
@@ -375,28 +391,29 @@ class ServingEngine:
         reg = self.run_registry = MetricsRegistry()
         c_real = reg.counter("engine_real_rows")
         c_padded = reg.counter("engine_padded_rows")
-        tr = obs_trace.get_tracer()
         drift_on = (self.predicted_step_s is not None
                     and get_drift_monitor() is not None)
         topo_fp = (topology_fingerprint(ops.get_default_hardware())
                    if drift_on else "")
-        t_prefill = 0.0
-        dispatch_acc: List[float] = []
+        t_admit_s = 0.0                      # host time spent admitting
+        dispatch_acc: List[float] = []       # per-step decode dispatch (s)
+        ready: List[Tuple[int, float]] = []  # (steps done, host clock)
         drained = False
         step = 0
         t_sync = None
 
         def admit(b: int) -> None:
-            nonlocal t_prefill, tokens
-            nonlocal cache
+            nonlocal t_admit_s, tokens, cache
+            t0 = time.perf_counter()
             req = self._queue.pop(0)
             plen = int(req.prompt.size)
-            prompt, last_pos, padded = self._padded(req.prompt)
-            t0 = time.perf_counter()
-            with (tr.span("prefill", cat="engine", track="engine",
-                          args={"rid": req.rid, "slot": b,
-                                "prompt_len": plen, "padded_len": padded})
-                  if tr is not None else obs_trace.NULL_SPAN):
+            padded = self.plan.bucket_for(plen) if self.plan else plen
+            with span("admit", on and {
+                    "rid": req.rid, "slot": b, "prompt_len": plen,
+                    "padded_len": padded,
+                    "queue_wait_ms": (t0 - req.t_submit) * 1e3,
+                    "queue_depth": len(self._queue)}):
+                prompt, last_pos, _ = self._padded(req.prompt)
                 logits, pc = retry(
                     lambda: self._prefill(self.params, prompt,
                                           req.extras or None, last_pos),
@@ -405,14 +422,14 @@ class ServingEngine:
                 cache = self._insert(cache, pc, jnp.int32(b))
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # (1,)
                 tokens = tokens.at[b].set(tok[0])
-            t_prefill += time.perf_counter() - t0
+            t_admit_s += time.perf_counter() - t0
             first_tok[req.rid] = tok
             slots[b].rid = req.rid
             slots[b].pos = plen
             slots[b].remaining = req.max_new_tokens - 1
             slots[b].admit_step = step
             pos_host[b] = plen
-            meta[req.rid] = (plen, padded, step)
+            meta[req.rid] = (plen, padded, step, req.t_submit, t0)
             reg.counter("engine_bucket_hits",
                         labels={"edge": str(padded)}).inc()
             c_real.inc(plen)
@@ -435,22 +452,24 @@ class ServingEngine:
                         admit(b)
                 if not any(s.active for s in slots):
                     break
-                pos_dev = jnp.asarray(pos_host, jnp.int32)
                 this_step = step
+                with span("upload", on and {"step": this_step}):
+                    pos_dev = jnp.asarray(pos_host, jnp.int32)
 
                 def body():
                     # Fault hook fires BEFORE decode: a retried step
                     # replays an intact (not-yet-donated) cache.
                     if self.decode_fault is not None:
-                        self.decode_fault(this_step, guard)
+                        with span("hook", on and {"step": this_step}):
+                            self.decode_fault(this_step, guard)
                     return self._decode(self.params, cache, tokens, pos_dev)
 
                 td0 = time.perf_counter()
-                with (tr.span("decode_step", cat="engine", track="engine",
-                              args={"step": this_step,
-                                    "active": sum(1 for s in slots
-                                                  if s.active)})
-                      if tr is not None else obs_trace.NULL_SPAN):
+                # Closes when the step is dispatched, not when it is done:
+                # ``sync`` ends when it is.
+                with span("decode_dispatch", on and {
+                        "step": this_step,
+                        "active": sum(1 for s in slots if s.active)}):
                     logits, cache = retry(
                         body, retries=_STEP_RETRIES,
                         base_delay=_STEP_BASE_DELAY,
@@ -459,37 +478,29 @@ class ServingEngine:
                     tokens = self._sample(logits, self._key(step)
                                           ).astype(jnp.int32)
                 dispatch_acc.append(time.perf_counter() - td0)
-                tok_log.append(tokens)
-                owners.append(tuple(s.rid for s in slots))
-                for b in range(B):
-                    s = slots[b]
-                    if not s.active:
-                        continue
-                    s.pos += 1
-                    pos_host[b] = s.pos
-                    s.remaining -= 1
-                    if s.remaining == 0:
-                        finished[s.rid] = step + 1
-                        s.rid = -1            # slot free: reused next admit
+                with span("bookkeeping", on and {"step": this_step}):
+                    tok_log.append(tokens)
+                    owners.append(tuple(s.rid for s in slots))
+                    for b in range(B):
+                        s = slots[b]
+                        if not s.active:
+                            continue
+                        s.pos += 1
+                        pos_host[b] = s.pos
+                        s.remaining -= 1
+                        if s.remaining == 0:
+                            finished[s.rid] = step + 1
+                            s.rid = -1        # slot free: reused next admit
                 step += 1
-                if step % self.sync_every == 0:
+                if step % self.sync_every:
+                    continue
+                with span("sync", on and {"step": this_step}):
                     tokens.block_until_ready()
+                with span("bookkeeping", on and {"step": this_step}):
                     now = time.perf_counter()
+                    ready.append((step, now))
                     window = now - (t_sync if t_sync is not None else t_run0)
                     t_sync = now
-                    n = min(self.sync_every, len(dispatch_acc))
-                    device_s = window / max(n, 1)
-                    dispatch_s = sum(dispatch_acc[-n:]) / max(n, 1)
-                    msg = self.straggler.record(device_s,
-                                                dispatch_s=dispatch_s)
-                    if msg:
-                        reg.counter("engine_straggler_flags").inc()
-                        obs_metrics.inc("engine_straggler_flags")
-                        obs_trace.event(
-                            "straggler_flag", cat="engine", track="engine",
-                            args={"step": step, "device_step_s": device_s,
-                                  "dispatch_s": dispatch_s, "msg": msg})
-                        self._status(msg)
                     if obs_metrics.metrics_enabled():
                         obs_metrics.set_gauge("engine_queue_depth",
                                               len(self._queue))
@@ -497,45 +508,49 @@ class ServingEngine:
                             "engine_slot_occupancy",
                             sum(1 for s in slots if s.active) / B)
                     if drift_on:
+                        n = min(self.sync_every, len(dispatch_acc))
                         record_step_drift(
                             site="decode_step", shape=(B,),
                             predicted_s=self.predicted_step_s,
-                            measured_s=device_s, topo=topo_fp,
-                            step=step, dispatch_s=dispatch_s)
-        jax.block_until_ready(tokens)
-        t_decode = time.perf_counter() - t_run0
+                            measured_s=window / max(n, 1), topo=topo_fp,
+                            step=step,
+                            dispatch_s=sum(dispatch_acc[-n:]) / max(n, 1))
         rem = step % self.sync_every
-        if rem:                   # tail window shorter than sync_every:
-            window = time.perf_counter() \
-                - (t_sync if t_sync is not None else t_run0)
-            self.straggler.record(
-                window / rem,
-                dispatch_s=sum(dispatch_acc[-rem:]) / rem)
+        with (span("sync", on and {"step": step - 1}) if rem
+              else obs_trace.NULL_SPAN):
+            jax.block_until_ready(tokens)
+        now = time.perf_counter()
+        t_decode = now - t_run0
+        if rem:                   # tail window shorter than sync_every
+            ready.append((step, now))
             if drift_on:
+                window = now - (t_sync if t_sync is not None else t_run0)
                 record_step_drift(
                     site="decode_step", shape=(B,),
                     predicted_s=self.predicted_step_s,
                     measured_s=window / rem, topo=topo_fp,
                     step=step, dispatch_s=sum(dispatch_acc[-rem:]) / rem)
 
-        # One transfer for the whole run: stack the device-side step log.
-        decoded = (np.asarray(jnp.stack(tok_log)) if tok_log
-                   else np.zeros((0, B), np.int32))
-        firsts = {r: int(np.asarray(t)[0]) for r, t in first_tok.items()}
-        results: Dict[int, RequestResult] = {}
-        emitted = 0
-        for rid, (plen, padded, adm) in meta.items():
-            fin = finished.get(rid, step)
-            cols = [firsts[rid]]
-            for s_ in range(adm, fin):
-                b = owners[s_].index(rid) if rid in owners[s_] else -1
-                if b >= 0:
-                    cols.append(int(decoded[s_, b]))
-            results[rid] = RequestResult(
-                rid=rid, prompt_len=plen, padded_len=padded,
-                tokens=np.asarray(cols, np.int32), admit_step=adm,
-                finish_step=fin, finished=rid in finished)
-            emitted += len(cols)
+        with span("collect"):
+            # One transfer for the whole run: stack the device-side step log.
+            decoded = (np.asarray(jnp.stack(tok_log)) if tok_log
+                       else np.zeros((0, B), np.int32))
+            firsts = {r: int(np.asarray(t)[0]) for r, t in first_tok.items()}
+            results: Dict[int, RequestResult] = {}
+            emitted = 0
+            for rid, (plen, padded, adm, t_sub, t_adm) in meta.items():
+                fin = finished.get(rid, step)
+                cols = [firsts[rid]]
+                for s_ in range(adm, fin):
+                    b = owners[s_].index(rid) if rid in owners[s_] else -1
+                    if b >= 0:
+                        cols.append(int(decoded[s_, b]))
+                results[rid] = RequestResult(
+                    rid=rid, prompt_len=plen, padded_len=padded,
+                    tokens=np.asarray(cols, np.int32), admit_step=adm,
+                    finish_step=fin, finished=rid in finished,
+                    t_submit=t_sub, t_admit=t_adm)
+                emitted += len(cols)
         # Stats come off the per-run registry where the accumulator was a
         # counter (same integer arithmetic as the old hand-rolled dicts, so
         # the public schema AND values are unchanged).
@@ -544,7 +559,8 @@ class ServingEngine:
         bucket_hits = {int(dict(m.labels)["edge"]): m.value
                        for m in reg.metrics()
                        if m.name == "engine_bucket_hits"}
-        tokens_per_s = emitted / max(t_decode + t_prefill, 1e-9)
+        # ``t_decode`` is the whole loop, admissions included.
+        tokens_per_s = emitted / max(t_decode, 1e-9)
         reg.counter("engine_steps").inc(step)
         reg.counter("engine_tokens_emitted").inc(emitted)
         reg.gauge("engine_tokens_per_s").set(tokens_per_s)
@@ -556,18 +572,14 @@ class ServingEngine:
             "steps": step,
             "drained": drained,
             "retries": self.retries,
-            "stragglers": list(self.straggler.flagged),
-            "t_prefill_s": t_prefill,
+            "t_admit_s": t_admit_s,
             "t_decode_s": t_decode,
             "tokens_emitted": emitted,
             "tokens_per_s": tokens_per_s,
             "bucket_hits": dict(sorted(bucket_hits.items())),
             "pad_fraction": pad_frac,
-            "dispatch_s_mean": (sum(dispatch_acc) / len(dispatch_acc)
-                                if dispatch_acc else 0.0),
-            "device_step_s_mean": (sum(self.straggler.times)
-                                   / len(self.straggler.times)
-                                   if self.straggler.times else 0.0),
+            "step_ready_s": ready,
+            "step_dispatch_s": dispatch_acc,
             "queued_left": len(self._queue),
             "residual_active": get_residual_corrector() is not None,
         }

@@ -16,8 +16,8 @@ families; exact by causality), finished sequences free their slot
 mid-decode, and every bucket edge's step GEMMs are warm-selected in one
 batched call before serving.  The decode loop is host-round-trip free:
 tokens stay on device until one end-of-run stack, RNG keys are pre-split
-per global step, and the StragglerMonitor reports pure device-step time
-next to host dispatch time.
+per global step, and the status line prints the median step time (from
+the engine's ready stamps at each sync) beside the median host dispatch.
 
 Set ``REPRO_SELECTION_CACHE=/path/to/selections.json`` to persist GEMM
 config selections across server processes: a warm restart replays every
@@ -92,8 +92,8 @@ def build_parser() -> argparse.ArgumentParser:
                     help="number of requests to serve "
                          "(default: --batch; ragged default: 2x)")
     ap.add_argument("--sync-every", type=int, default=8,
-                    help="decode steps between device syncs (straggler "
-                         "sampling granularity)")
+                    help="decode steps between device syncs (the "
+                         "granularity of the step-time stamps)")
     ap.add_argument("--quiet", action="store_true",
                     help="suppress stdout status lines (they still flow "
                          "through the trace/metrics layer)")
@@ -119,9 +119,9 @@ def run_serving(args: argparse.Namespace, *,
     generated array including the prefill token; ragged mode: a list of
     per-request arrays), ``drained`` (True when a preemption request
     stopped decode early), ``steps`` (decode steps completed), ``retries``
-    (transient retries absorbed), ``stragglers``, timings, engine stats
-    (``pad_fraction``, ``bucket_hits``, ``dispatch_s_mean``,
-    ``device_step_s_mean``, ``tokens_per_s``), the topology served
+    (transient retries absorbed), timings (``t_admit_s``, the host time
+    spent admitting; ``t_decode_s``), engine stats (``pad_fraction``,
+    ``bucket_hits``, ``tokens_per_s``), the topology served
     against (plus ``degraded`` when the artifact was rejected), the
     request ``prompts`` in rid order, and the ``engine`` itself (its
     programs stay compiled for probes and reruns).
@@ -281,8 +281,7 @@ def _run_serving(args: argparse.Namespace, *,
         model, params, max_batch=args.batch, max_len=max_len, plan=plan,
         temperature=args.temperature, seed=args.seed,
         sync_every=getattr(args, "sync_every", 8),
-        decode_fault=decode_fault,
-        straggler_window=16, straggler_min_steps=4, quiet=quiet,
+        decode_fault=decode_fault, quiet=quiet,
         mesh=mesh)
 
     def _extras(i):
@@ -315,11 +314,13 @@ def _run_serving(args: argparse.Namespace, *,
 
     toks_per_s = stats["tokens_per_s"]
     say(f"arch={cfg.name} batch={args.batch} requests={n_req} "
-        f"prefill {args.prompt_len} tok in "
-        f"{stats['t_prefill_s'] * 1e3:.0f}ms; "
+        f"prefill {args.prompt_len} tok; admitting took "
+        f"{stats['t_admit_s'] * 1e3:.0f}ms of host time; "
         f"decoded {n_steps} steps at {toks_per_s:.1f} tok/s total")
-    say(f"[serve] dispatch {stats['dispatch_s_mean'] * 1e3:.2f}ms/step "
-        f"vs device {stats['device_step_s_mean'] * 1e3:.2f}ms/step; "
+    say(f"[serve] step {median_step_s(stats) * 1e3:.2f}ms "
+        f"(median between syncs) vs dispatch "
+        f"{np.median(stats['step_dispatch_s'] or [0.0]) * 1e3:.2f}ms "
+        f"(median); "
         f"padding {stats['pad_fraction'] * 100:.1f}%; "
         f"bucket hits {stats['bucket_hits']}")
     show = tokens if ragged else tokens[:2]
@@ -331,14 +332,11 @@ def _run_serving(args: argparse.Namespace, *,
         "steps": n_steps,
         "drained": stats["drained"],
         "retries": stats["retries"],
-        "stragglers": stats["stragglers"],
-        "t_prefill_s": stats["t_prefill_s"],
+        "t_admit_s": stats["t_admit_s"],
         "t_decode_s": stats["t_decode_s"],
         "tokens_per_s": toks_per_s,
         "pad_fraction": stats["pad_fraction"],
         "bucket_hits": stats["bucket_hits"],
-        "dispatch_s_mean": stats["dispatch_s_mean"],
-        "device_step_s_mean": stats["device_step_s_mean"],
         "residual_active": stats["residual_active"],
         "results": results,
         "prompts": [prompts[i, :lens[i]] for i in range(n_req)],
@@ -346,6 +344,14 @@ def _run_serving(args: argparse.Namespace, *,
         **topo_info,
         **res_info,
     }
+
+
+def median_step_s(stats: Dict) -> float:
+    """Median seconds per decode step between consecutive sync stamps
+    (``step_ready_s``); 0.0 with fewer than two."""
+    r = stats["step_ready_s"]
+    per = [(t1 - t0) / (n1 - n0) for (n0, t0), (n1, t1) in zip(r, r[1:])]
+    return float(np.median(per)) if per else 0.0
 
 
 def main() -> int:

@@ -4,7 +4,9 @@ Three dependency-free pillars, all off by default:
 
 * :mod:`repro.obs.trace`   — structured spans/events with an injectable
   clock and deterministic sortable span ids; the Chrome/Perfetto exporter
-  lives in :mod:`repro.obs.perfetto`.
+  lives in :mod:`repro.obs.perfetto`.  ``Tracer(profiler=True)`` also
+  writes each span into the JAX profiler's trace (``jax`` is imported only
+  for it).
 * :mod:`repro.obs.metrics` — a process-global registry of counters /
   gauges / histograms with JSONL and Prometheus-textfile exporters.
 * :mod:`repro.obs.drift`   — the model-vs-measured drift monitor: one

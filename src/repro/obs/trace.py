@@ -9,6 +9,12 @@ byte-deterministic trace.  ``Tracer.to_json``/``from_json`` round-trip the
 full schema; the Chrome/Perfetto ``trace.json`` exporter is
 :mod:`repro.obs.perfetto`.
 
+With ``Tracer(profiler=True)`` every :meth:`Tracer.span` also enters a
+``jax.profiler.TraceAnnotation`` named ``<track>.<name>`` (``engine.admit``)
+whose keyword arguments are the span's args: while the JAX profiler runs,
+the span lands on the profiler's host timeline, with its args as event
+stats, beside the device's programs.  ``jax`` is imported only then.
+
 Off by default: the module-global tracer is ``None`` until
 :func:`set_tracer` installs one.  The instrumentation helpers (:func:`span`,
 :func:`event`, :func:`counter`) cost one global load + ``is None`` check and
@@ -72,19 +78,25 @@ class Span:
 
 class _OpenSpan:
     """Context manager closing one span on exit (reused per ``Tracer.span``
-    call; only allocated when tracing is ON)."""
+    call; only allocated when tracing is ON).  ``annotation``, when given,
+    is the profiler annotation entered and exited with the span."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation=None):
         self._tracer = tracer
         self._span = span
+        self._annotation = annotation
 
     def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> None:
         self._span.end = self._tracer.now()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class _NullSpan:
@@ -106,15 +118,21 @@ NULL_SPAN = _NullSpan()
 class Tracer:
     """Collects spans.  ``clock`` is injectable (defaults to a zero-based
     ``time.perf_counter``) so tests can pin timestamps; span ids count up
-    from 0 in emission order."""
+    from 0 in emission order.  ``profiler=True`` also puts every span into
+    the JAX profiler's trace (module docstring)."""
 
-    def __init__(self, clock: Optional[Callable[[], float]] = None):
+    def __init__(self, clock: Optional[Callable[[], float]] = None,
+                 profiler: bool = False):
         if clock is None:
             t0 = time.perf_counter()
             clock = lambda: time.perf_counter() - t0        # noqa: E731
         self._clock = clock
         self._next = 0
         self.spans: List[Span] = []
+        self._annotation = None
+        if profiler:
+            import jax.profiler
+            self._annotation = jax.profiler.TraceAnnotation
 
     def now(self) -> float:
         return self._clock()
@@ -129,8 +147,10 @@ class Tracer:
     def span(self, name: str, cat: str = "", track: str = "main",
              args: Optional[Dict] = None) -> _OpenSpan:
         """Open a duration span; closes (stamps ``end``) on ``__exit__``."""
+        annotation = (None if self._annotation is None
+                      else self._annotation(f"{track}.{name}", **(args or {})))
         return _OpenSpan(self, self._emit(name, cat, track, self.now(),
-                                          None, args))
+                                          None, args), annotation)
 
     def complete(self, name: str, cat: str, track: str, start: float,
                  end: float, args: Optional[Dict] = None) -> Span:

@@ -116,6 +116,11 @@ def test_disabled_path_allocates_nothing(clean_obs, tmp_path):
         obs_trace.counter("c", 1.0)
     assert obs_trace.Span.allocated == before          # zero Span objects
     assert obs_trace.span("again") is obs_trace.NULL_SPAN  # shared singleton
+    # A profiler-backed tracer that is not installed changes nothing.
+    obs_trace.Tracer(profiler=True)
+    with obs_trace.span("hot", cat="x", track="t") as s:
+        assert s is None
+    assert obs_trace.Span.allocated == before
     # Disabled metrics helpers: global registry stays empty.
     obs_metrics.inc("nope")
     obs_metrics.set_gauge("nope_g", 1.0)
@@ -437,7 +442,7 @@ def test_perfetto_export_loadable(clean_obs, tmp_path):
 # Engine: tracing observes, never perturbs
 # ---------------------------------------------------------------------------
 
-def test_engine_tracing_identical_output(clean_obs):
+def _tiny_engine_setup():
     cfg = get_config("phi4-mini-3.8b", smoke=True)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -451,17 +456,26 @@ def test_engine_tracing_identical_output(clean_obs):
                                vocab=cfg.vocab_size,
                                swiglu=cfg.activation == "swiglu"),
         hw=ops.get_default_hardware(), max_buckets=2)
+    return model, params, prompts, plan
+
+
+def test_engine_tracing_identical_output(clean_obs):
+    model, params, prompts, plan = _tiny_engine_setup()
+    hooked = []
 
     def run_once():
         eng = ServingEngine(model, params, max_batch=2, max_len=64,
                             plan=plan, temperature=0.0, seed=0,
-                            sync_every=4, quiet=True)
+                            sync_every=1, quiet=True,
+                            decode_fault=lambda step, g: hooked.append(step))
         for p in prompts:
             eng.submit(p, max_new_tokens=3)
         eng.warm_start()
         return eng.run()
 
+    before = obs_trace.Span.allocated
     off = run_once()
+    assert obs_trace.Span.allocated == before     # no tracer: no Span built
     tr = obs_trace.Tracer()
     obs_trace.set_tracer(tr)
     obs_metrics.enable_metrics(True)
@@ -474,18 +488,113 @@ def test_engine_tracing_identical_output(clean_obs):
     for key in ("steps", "drained", "retries", "bucket_hits",
                 "pad_fraction", "tokens_emitted", "queued_left"):
         assert off[key] == on[key], key
-    # The traced run produced the span taxonomy DESIGN.md §11 documents.
-    names = {s.name for s in tr.spans}
-    assert {"warm_start", "prefill", "decode_step"} <= names
-    prefills = [s for s in tr.spans if s.name == "prefill"]
-    assert len(prefills) == len(prompts)
-    assert all(s.kind == "span" for s in prefills)
-    decodes = [s for s in tr.spans if s.name == "decode_step"]
-    assert len(decodes) == on["steps"]
+    # The traced run produced the span taxonomy DESIGN.md §11 documents,
+    # and nothing under the names they replaced.
+    eng = [s for s in tr.spans if s.track == "engine"]
+    names = {s.name for s in eng}
+    assert {"warm_start", "init", "admit", "upload", "hook",
+            "decode_dispatch", "sync", "bookkeeping", "collect"} <= names
+    assert not names & {"prefill", "decode_step", "straggler_flag"}
+    assert all(s.kind == "span" for s in eng if s.name != "status")
+    steps = on["steps"]
+
+    def of(name):
+        return [s for s in eng if s.name == name]
+    admits = of("admit")
+    assert len(admits) == len(prompts)
+    assert sorted(s.args["rid"] for s in admits) == list(range(len(prompts)))
+    for s in admits:
+        res = on["results"][s.args["rid"]]
+        assert s.args["prompt_len"] == res.prompt_len
+        assert s.args["padded_len"] == res.padded_len
+        assert s.args["queue_wait_ms"] == pytest.approx(
+            (res.t_admit - res.t_submit) * 1e3)
+        assert 0 < res.t_submit <= res.t_admit
+    # One dispatch, one upload, one hook and one sync per step (sync_every
+    # 1), each carrying its step; the hook runs inside the dispatch.
+    for name in ("decode_dispatch", "upload", "hook", "sync"):
+        assert [s.args["step"] for s in of(name)] == list(range(steps)), name
+    for d, h in zip(of("decode_dispatch"), of("hook")):
+        assert d.start <= h.start <= h.end <= d.end
+    assert all(s.args["active"] >= 1 for s in of("decode_dispatch"))
+    # A step's sync ends before the next step's upload starts.
+    for sy, up in zip(of("sync"), of("upload")[1:]):
+        assert sy.end <= up.start
+    assert len(of("init")) == len(of("collect")) == 1
+    # Every step's ready stamp, in order.
+    assert [n for n, _ in on["step_ready_s"]] == list(range(1, steps + 1))
+    assert len(on["step_dispatch_s"]) == steps
+    assert on["tokens_per_s"] == pytest.approx(
+        on["tokens_emitted"] / on["t_decode_s"])
     # Engine counters were merge-published into the global registry.
     snap = obs_metrics.get_registry().snapshot()
     assert snap["engine_steps"] == on["steps"]
     assert snap["engine_tokens_emitted"] == on["tokens_emitted"]
+    assert "engine_straggler_flags" not in snap
+
+
+def test_engine_syncs_every_window_and_the_tail(clean_obs):
+    """With sync_every=4 the engine blocks at each fourth step and at run
+    end: one ``sync`` span and one ready stamp per window."""
+    model, params, prompts, plan = _tiny_engine_setup()
+    tr = obs_trace.Tracer()
+    obs_trace.set_tracer(tr)
+    eng = ServingEngine(model, params, max_batch=2, max_len=64, plan=plan,
+                        sync_every=4, quiet=True)
+    for p in prompts:
+        eng.submit(p, max_new_tokens=4)
+    stats = eng.run()
+    obs_trace.set_tracer(None)
+    steps = stats["steps"]
+    marks = list(range(4, steps + 1, 4))
+    if steps % 4:
+        marks.append(steps)
+    assert [n for n, _ in stats["step_ready_s"]] == marks
+    syncs = [s for s in tr.spans if s.name == "sync"]
+    assert [s.args["step"] + 1 for s in syncs] == marks
+    stamps = [t for _, t in stats["step_ready_s"]]
+    assert stamps == sorted(stamps)
+
+
+def test_obs_imports_nothing_of_core_or_launch():
+    """DESIGN.md §11's import rule, in a fresh interpreter: importing
+    ``repro.obs`` (and building a profiler tracer) pulls in no module of
+    ``repro.core`` or ``repro.launch``."""
+    import subprocess
+    import sys
+    code = ("import sys; import repro.obs; "
+            "repro.obs.Tracer(profiler=True); "
+            "print([m for m in sys.modules "
+            "if m.startswith(('repro.core', 'repro.launch'))])")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, check=True,
+                         env=dict(os.environ, PYTHONPATH=src,
+                                  JAX_PLATFORMS="cpu"))
+    assert out.stdout.strip() == "[]"
+
+
+def test_profiler_tracer_puts_spans_in_the_profiler_trace(clean_obs,
+                                                          tmp_path):
+    """``Tracer(profiler=True)``: each span is also a profiler annotation
+    named ``<track>.<name>`` carrying its args as event stats; the
+    in-memory span list is unchanged."""
+    import glob
+    from jax.profiler import ProfileData
+    tr = obs_trace.Tracer(profiler=True)
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("admit", cat="engine", track="engine",
+                 args={"rid": 7, "queue_wait_ms": 1.5}):
+        tr.event("inner", track="engine")
+    jax.profiler.stop_trace()
+    assert [s.name for s in tr.spans] == ["admit", "inner"]
+    assert tr.spans[0].end is not None
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [dict(e.stats) for p in ProfileData.from_file(path).planes
+             for ln in p.lines for e in ln.events if e.name == "engine.admit"]
+    assert len(found) == 1
+    assert found[0]["rid"] == 7 and found[0]["queue_wait_ms"] == 1.5
 
 
 def test_obs_report_skips_truncated_jsonl_tail(clean_obs, tmp_path):
