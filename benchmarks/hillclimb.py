@@ -35,11 +35,9 @@ RUNS = [
     ("mixtral-8x22b", "train_4k", "kvrep", ["--kv-repeat-weights"]),
     ("mixtral-8x22b", "train_4k", "kvrep_mb", ["--kv-repeat-weights",
                                                "--microbatches", "0"]),
-    ("qwen3-moe-30b-a3b", "decode_32k", "gqapack", ["--gqa-packed-decode"]),
-    ("qwen3-moe-30b-a3b", "decode_32k", "gqapack_moedense",
-     ["--gqa-packed-decode", "--moe-dense-decode"]),
-    ("qwen3-moe-30b-a3b", "decode_32k", "gqapack_moedense_kvrep",
-     ["--gqa-packed-decode", "--moe-dense-decode", "--kv-repeat-weights"]),
+    ("qwen3-moe-30b-a3b", "decode_32k", "moedense", ["--moe-dense-decode"]),
+    ("qwen3-moe-30b-a3b", "decode_32k", "moedense_kvrep",
+     ["--moe-dense-decode", "--kv-repeat-weights"]),
     # Attribution runs for the bf16-TP-reduction change (kernels/ref.py):
     # no flags => isolates the pure bf16-collective effect vs baseline.
     ("internlm2-20b", "train_4k", "bf16coll", []),

@@ -144,7 +144,7 @@ def _lower_cell(model, cfg, shape, mesh, microbatches: int = 1):
 
 def run_probes(arch: str, shape_name: str, multi_pod: bool,
                verbose: bool = True, microbatches: int = 1,
-               sp_stash: bool = False, gqa_packed_decode: bool = False,
+               sp_stash: bool = False,
                kv_repeat_weights: bool = False,
                moe_dense_decode: bool = False,
                moe_local_dispatch: bool = False) -> dict:
@@ -154,8 +154,6 @@ def run_probes(arch: str, shape_name: str, multi_pod: bool,
     base_cfg = get_config(arch)
     if sp_stash:
         base_cfg = dataclasses.replace(base_cfg, sp_stash=True)
-    if gqa_packed_decode:
-        base_cfg = dataclasses.replace(base_cfg, gqa_packed_decode=True)
     if kv_repeat_weights:
         base_cfg = dataclasses.replace(base_cfg, kv_repeat_weights=True)
     if moe_dense_decode:
@@ -203,7 +201,7 @@ def run_probes(arch: str, shape_name: str, multi_pod: bool,
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              out_dir: str = "experiments/dryrun", verbose: bool = True,
              with_probes: bool = False, microbatches: int = 1,
-             sp_stash: bool = False, gqa_packed_decode: bool = False,
+             sp_stash: bool = False,
              kv_repeat_weights: bool = False,
              moe_dense_decode: bool = False,
              moe_local_dispatch: bool = False,
@@ -211,8 +209,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     cfg = get_config(arch)
     if sp_stash:
         cfg = dataclasses.replace(cfg, sp_stash=True)
-    if gqa_packed_decode:
-        cfg = dataclasses.replace(cfg, gqa_packed_decode=True)
     if kv_repeat_weights:
         cfg = dataclasses.replace(cfg, kv_repeat_weights=True)
     if moe_dense_decode:
@@ -266,7 +262,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "microbatches": microbatches,
         "sp_stash": sp_stash,
         "kv_repeat_weights": kv_repeat_weights,
-        "gqa_packed_decode": gqa_packed_decode,
         "moe_dense_decode": moe_dense_decode,
         "moe_local_dispatch": moe_local_dispatch,
         "memory": _mem_stats(compiled),
@@ -282,7 +277,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     if with_probes:
         probes = run_probes(arch, shape_name, multi_pod, verbose=verbose,
                             microbatches=microbatches, sp_stash=sp_stash,
-                            gqa_packed_decode=gqa_packed_decode,
                             kv_repeat_weights=kv_repeat_weights,
                             moe_dense_decode=moe_dense_decode,
                             moe_local_dispatch=moe_local_dispatch)
@@ -340,8 +334,6 @@ def main() -> int:
     ap.add_argument("--sp-stash", action="store_true",
                     help="sequence-shard the residual stream at scan "
                          "boundaries (SP remat stash)")
-    ap.add_argument("--gqa-packed-decode", action="store_true",
-                    help="grouped-query decode attention (no KV repeat)")
     ap.add_argument("--kv-repeat-weights", action="store_true",
                     help="Megatron KV-weight duplication (TP > Hkv)")
     ap.add_argument("--moe-dense-decode", action="store_true",
@@ -370,7 +362,6 @@ def main() -> int:
                          with_probes=args.with_probes,
                          microbatches=args.microbatches,
                          sp_stash=args.sp_stash,
-                         gqa_packed_decode=args.gqa_packed_decode,
                          kv_repeat_weights=args.kv_repeat_weights,
                          moe_dense_decode=args.moe_dense_decode,
                          moe_local_dispatch=args.moe_local_dispatch)

@@ -6,16 +6,20 @@ It is the dry-run / CPU / GSPMD path; its FLOP and byte profile matches the
 Pallas kernel algorithm, which is what the roofline reads.  On TPU runtimes
 ``repro.kernels.flash_attention`` (selector-tiled Pallas) is used instead.
 
-GQA note (sharding-critical): q stays (B, H, S, d) and KV is broadcast to H
-heads with jnp.repeat.  H divides the 16-way "model" axis for every
-assigned arch, whereas a (B, Hkv, group, S, d) grouping would leave GSPMD
-with two non-dividing head dims (Hkv=8, group=6) and force *full attention
-replication* on every chip — a 16x flop/byte blow-up we measured in the
-dry-run probes (EXPERIMENTS.md §Perf, iteration 1).
+GQA note (sharding-critical): in ``chunked_attention`` q stays (B, H, S, d)
+and KV is broadcast to H heads with jnp.repeat.  H divides the 16-way
+"model" axis for every assigned arch, whereas a (B, Hkv, group, S, d)
+grouping would leave GSPMD with two non-dividing head dims (Hkv=8, group=6)
+and force *full attention replication* on every chip — a 16x flop/byte
+blow-up we measured in the dry-run probes (EXPERIMENTS.md §Perf, iteration
+1).
 
-``decode_attention`` scores one query step against a long KV cache; with the
-cache's sequence axis sharded over the "model" mesh axis this becomes
-flash-decode (partial softmax + cross-chip reduction, inserted by GSPMD).
+``decode_attention`` scores one query step against a long KV cache and never
+repeats it: the decode cache shards on SEQUENCE, not heads, so no head dim
+must divide the "model" axis, and the queries are grouped by kv head
+instead.  With the cache's sequence axis sharded over "model" this becomes
+flash-decode (partial softmax + cross-chip reduction, inserted by GSPMD);
+the cache itself never moves between chips.
 """
 from __future__ import annotations
 
@@ -123,18 +127,17 @@ def decode_attention(
     k_cache: jax.Array,              # (B, Hkv, S, d)
     v_cache: jax.Array,              # (B, Hkv, S, d)
     *,
-    pos: jax.Array,                  # current length (scalar int32)
+    pos: jax.Array,                  # current length: scalar or (B,) int32
     sliding_window: int = 0,
     scale: Optional[float] = None,
-    gqa_packed: bool = False,
 ) -> jax.Array:
     """Flash-decode: one query step against the cache.
 
-    ``gqa_packed=True`` keeps KV un-repeated and scores grouped queries
-    against their shared kv head (§Perf iteration: decode is KV-read-bound
-    and the repeat multiplies HBM traffic by H/Hkv; packing is legal here
-    because the decode cache shards on SEQUENCE, not heads — unlike the
-    training path, no dim must divide the "model" axis)."""
+    The H queries are grouped by kv head, (B, Hkv, G, d) with G = H // Hkv
+    (MHA is G = 1), and each group is scored against its head's cache as
+    stored: the cache is never repeated to H heads nor copied to f32.  Both
+    contractions take their operands in the cache's dtype and accumulate
+    in f32; mask, max, exp and sum run in f32."""
     B, H, _, d = q.shape
     _, Hkv, S, _ = k_cache.shape
     group = H // Hkv
@@ -145,41 +148,22 @@ def decode_attention(
         mask = k_pos <= pos
         if sliding_window > 0:
             mask = mask & (pos - k_pos < sliding_window)
-        mask_packed = mask.reshape(1, 1, 1, S)
-        mask_flat = mask.reshape(1, 1, S)
+        mask = mask.reshape(1, 1, 1, S)
     else:
         # Per-slot positions (continuous batching): each row masks its own
         # prefix, so slots mid-decode coexist with freshly admitted ones.
         mask = k_pos[None, :] <= pos[:, None]                 # (B, S)
         if sliding_window > 0:
             mask = mask & (pos[:, None] - k_pos[None, :] < sliding_window)
-        mask_packed = mask[:, None, None, :]
-        mask_flat = mask[:, None, :]
+        mask = mask[:, None, None, :]
 
-    if group > 1 and gqa_packed:
-        qg = q[:, :, 0].reshape(B, Hkv, group, d).astype(jnp.float32) * scale
-        s = jnp.einsum("bhgd,bhkd->bhgk", qg,
-                       k_cache.astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(mask_packed, s, NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
-        out = jnp.einsum("bhgk,bhkd->bhgd", p,
-                         v_cache.astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
-        return out.reshape(B, H, 1, d).astype(q.dtype)
-
-    if group > 1:
-        k_cache = jnp.repeat(k_cache, group, axis=1)
-        v_cache = jnp.repeat(v_cache, group, axis=1)
-    qh = q[:, :, 0].astype(jnp.float32) * scale          # (B, H, d)
-    s = jnp.einsum("bhd,bhkd->bhk", qh, k_cache.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(mask_flat, s, NEG_INF)
+    qg = q.reshape(B, Hkv, group, d).astype(k_cache.dtype)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k_cache,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhk,bhkd->bhd", p, v_cache.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-    return out[:, :, None].astype(q.dtype)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhgk,bhkd->bhgd", p.astype(v_cache.dtype), v_cache,
+                     preferred_element_type=jnp.float32) / l
+    return out.reshape(B, H, 1, d).astype(q.dtype)

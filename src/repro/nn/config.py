@@ -50,9 +50,6 @@ class ModelConfig:
     # divides the remat stash by the "model" axis size at the cost of
     # gather/scatter collectives around attention (EXPERIMENTS.md §Perf).
     sp_stash: bool = False
-    # Grouped-query decode attention (no KV repeat): divides decode KV HBM
-    # traffic by H/Hkv (EXPERIMENTS.md §Perf).
-    gqa_packed_decode: bool = False
     # Repeat KV projection *weights* to H heads at trace time (Megatron's
     # KV duplication for TP > Hkv): kills the per-layer all-gather of K/V
     # activations that GSPMD inserts when Hkv doesn't divide the "model"

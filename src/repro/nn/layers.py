@@ -320,8 +320,7 @@ def attn_decode(
         k_cache = upd(cache["k"], k, pos)
         v_cache = upd(cache["v"], v, pos)
     out = attn_lib.decode_attention(
-        q, k_cache, v_cache, pos=pos, sliding_window=cfg.sliding_window,
-        gqa_packed=cfg.gqa_packed_decode)
+        q, k_cache, v_cache, pos=pos, sliding_window=cfg.sliding_window)
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd)
     return (dense(out, p["wo"], axes=ax["wo"]),
             {"k": k_cache, "v": v_cache})

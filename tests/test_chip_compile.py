@@ -19,10 +19,11 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.configs.registry import get_config
 from repro.core.bucketing import step_gemms
-from repro.distributed.sharding import rules_for, spec_for
+from repro.distributed.sharding import cache_shardings, rules_for, spec_for
 from repro.kernels import ops
 from repro.meshctx import use_mesh
 from repro.nn import layers as L
+from repro.nn.attention import decode_attention
 
 CFG = get_config("phi4-mini-3.8b")
 # (N, K) of one decoder step: fused QKV, attention out, MLP up+gate, MLP
@@ -126,3 +127,44 @@ def test_dense_compiles_on_four_chips(topo, pallas, name):
     assert "tpu_custom_call" in text
     assert "all-gather" not in text
     assert ("all-reduce" in text) == (w_spec[0] == "model")
+
+
+def _decode_specs(B, H, Hkv, S, d, q_sh, kv_sh, pos_sh):
+    q = jax.ShapeDtypeStruct((B, H, 1, d), jnp.bfloat16, sharding=q_sh)
+    kv = jax.ShapeDtypeStruct((B, Hkv, S, d), jnp.bfloat16, sharding=kv_sh)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=pos_sh)
+    return q, kv, kv, pos
+
+
+def _decode(q, k, v, pos):
+    return decode_attention(q, k, v, pos=pos)
+
+
+def test_decode_attention_holds_no_cache_copy(one_chip):
+    """phi4's decode attention at the serving shapes (12 slots of 2048,
+    per-slot positions) reads the bf16 cache as it lies: its scratch stays
+    below one layer's K cache, where a repeated f32 copy needs six."""
+    B, S, d = 12, 2048, CFG.head_dim
+    specs = _decode_specs(B, CFG.num_heads, CFG.num_kv_heads, S, d,
+                          one_chip, one_chip, one_chip)
+    mem = jax.jit(_decode).lower(*specs).compile().memory_analysis()
+    k_cache_bytes = B * CFG.num_kv_heads * S * d * 2
+    assert mem.temp_size_in_bytes < k_cache_bytes
+
+
+def test_decode_attention_on_four_chips_moves_no_cache(topo):
+    """Minitron-8B's decode attention (48 heads over 8 kv heads, 16 slots
+    of 2048) on a (1, 4) mesh, the cache laid out by ``cache_shardings``
+    (sequence over "model"): flash-decode over the split cache, so no
+    all-to-all moves the cache from a sequence split to a head split."""
+    B, H, Hkv, S, d = 16, 48, 8, 2048, 128
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    layer = jax.ShapeDtypeStruct((1, B, Hkv, S, d), jnp.bfloat16)
+    kv_sh = cache_shardings({"k": layer}, mesh, CFG)["k"]
+    assert "model" in kv_sh.spec
+    specs = _decode_specs(
+        B, H, Hkv, S, d, NamedSharding(mesh, P(None, "model", None, None)),
+        NamedSharding(mesh, P(*kv_sh.spec[1:])), NamedSharding(mesh, P()))
+    text = _compiled_text(_decode, *specs)
+    assert "all-to-all" not in text

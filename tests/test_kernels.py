@@ -272,18 +272,49 @@ def test_chunked_attention_vs_ref(causal, window):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
-def test_decode_attention_gqa_packed_equivalence():
-    """Packed grouped-query decode (no KV repeat — §Perf) must equal the
-    repeat formulation bit-for-bit up to float tolerance."""
+def _decode_ref(q, k, v, pos, window):
+    """numpy float32 decode attention: K/V repeated to H heads, then a
+    masked softmax over each row's own prefix."""
+    q, k, v = (np.asarray(x, dtype=np.float32) for x in (q, k, v))
+    B, H, _, d = q.shape
+    S = k.shape[2]
+    group = H // k.shape[1]
+    k, v = np.repeat(k, group, axis=1), np.repeat(v, group, axis=1)
+    pos = np.broadcast_to(np.asarray(pos), (B,))[:, None]
+    k_pos = np.arange(S)[None, :]
+    mask = k_pos <= pos
+    if window:
+        mask &= pos - k_pos < window
+    s = np.einsum("bhd,bhkd->bhk", q[:, :, 0], k) * d ** -0.5
+    s = np.where(mask[:, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhk,bhkd->bhd", p, v)[:, :, None]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [0, 16])
+@pytest.mark.parametrize("ragged", [False, True],
+                         ids=["scalar_pos", "ragged_pos"])
+@pytest.mark.parametrize("group", [1, 3, 6])
+def test_decode_attention_vs_ref(group, ragged, window, dtype):
+    """Grouped-query decode over the un-repeated cache against an
+    independent f32 reference; bf16 allows for q, p and the output
+    rounded to bf16."""
     from repro.nn.attention import decode_attention
-    B, H, Hkv, S, d = 2, 6, 2, 64, 16
-    q = jnp.asarray(RNG.standard_normal((B, H, 1, d)), dtype=jnp.float32)
-    k = jnp.asarray(RNG.standard_normal((B, Hkv, S, d)), dtype=jnp.float32)
-    v = jnp.asarray(RNG.standard_normal((B, Hkv, S, d)), dtype=jnp.float32)
-    a = np.asarray(decode_attention(q, k, v, pos=jnp.int32(S - 1)))
-    b = np.asarray(decode_attention(q, k, v, pos=jnp.int32(S - 1),
-                                    gqa_packed=True))
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    B, Hkv, S, d = 3, 2, 64, 32
+    H = Hkv * group
+    dt = jnp.dtype(dtype)
+    q, k, v = (jnp.asarray(RNG.standard_normal(shape), dtype=dt)
+               for shape in ((B, H, 1, d), (B, Hkv, S, d), (B, Hkv, S, d)))
+    pos = (jnp.asarray([S - 1, 5, 37], jnp.int32) if ragged
+           else jnp.int32(S - 9))
+    got = decode_attention(q, k, v, pos=pos, sliding_window=window)
+    assert got.shape == q.shape and got.dtype == dt
+    want = _decode_ref(q, k, v, pos, window)
+    tol = 1e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
 
 
 def test_decode_attention_matches_prefix():
